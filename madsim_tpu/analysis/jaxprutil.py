@@ -4,7 +4,7 @@ The jaxpr-level rules (madsim_tpu/analysis/jaxpr_check.py) all reduce to
 three primitives implemented here:
 
   * `iter_eqns` — walk every equation of a closed jaxpr INCLUDING the
-    sub-jaxprs nested in pjit / while / scan / cond / custom_* params,
+    sub-jaxprs nested in jit / while / scan / cond / custom_* params,
     so a callback or cross-lane reduction can't hide inside a call.
   * `TaintMap` — forward data-flow of a tiny 4-bit taint lattice
     (KEY / STATE / TIME / SALT) from the function's invars through every
@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import numpy as np
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 # murmur3 constants (tpu/prng.py / nemesis.mix32): the fmix multiplies
 # identify mix equations; GOLDEN identifies fold word-multiplies.
@@ -76,9 +76,14 @@ def scalar_value(x: Any) -> Optional[int]:
     return int(arr)
 
 
+def is_literal(atom: Any) -> bool:
+    """True for a jaxpr Literal atom (an inline constant, not a Var)."""
+    return isinstance(atom, jcore.Literal)
+
+
 def lit_value(atom: Any) -> Optional[int]:
     """Scalar int value of a jaxpr Literal atom, else None."""
-    if isinstance(atom, jcore.Literal):
+    if is_literal(atom):
         return scalar_value(atom.val)
     return None
 
@@ -138,7 +143,7 @@ class TaintMap:
     way); integer-valued coupling (index=clock and friends) is still
     caught. Sub-jaxpr handling (r9, grown for the refill step's
     lax.cond):
-    `pjit` and `cond` bodies are entered with each inner invar seeded by
+    `jit` and `cond` bodies are entered with each inner invar seeded by
     its MATCHING operand's mask (precise 1:1 mapping — the old
     union-of-all-operands seeding made every value inside the refill
     branch carry every taint at once), and their per-branch outvar masks
@@ -184,7 +189,7 @@ class TaintMap:
         lv = lit_value(atom)
         if lv is not None and lv in self.salt_values:
             return SALT
-        if isinstance(atom, jcore.Literal):
+        if is_literal(atom):
             return 0
         return self.env.get(atom, 0)
 
@@ -242,12 +247,12 @@ class TaintMap:
                 visit(eqn, self.read)
             name = eqn.primitive.name
             subs = _sub_jaxprs(eqn)
-            # precise call handling: pjit (1:1 invars) and cond (operand
+            # precise call handling: jit (1:1 invars) and cond (operand
             # k+1 -> branch invar k; outvars joined across branches, the
             # predicate excluded — control flow doesn't launder data
             # taint). Shape-mismatched calls fall through to the
             # conservative union path below.
-            if name == "pjit" and len(subs) == 1 and len(
+            if name == "jit" and len(subs) == 1 and len(
                 subs[0][0].invars
             ) == len(eqn.invars):
                 in_masks = [self.read(iv) for iv in eqn.invars]
@@ -391,7 +396,7 @@ def backward_invars(jaxpr: jcore.Jaxpr, seeds: Sequence[Any]) -> List[int]:
     invar_pos = {v: i for i, v in enumerate(jaxpr.invars)}
     seen: set = set()
     hits: set = set()
-    stack = [s for s in seeds if not isinstance(s, jcore.Literal)]
+    stack = [s for s in seeds if not is_literal(s)]
     while stack:
         v = stack.pop()
         if id(v) in seen:
@@ -404,7 +409,7 @@ def backward_invars(jaxpr: jcore.Jaxpr, seeds: Sequence[Any]) -> List[int]:
         if eqn is None:
             continue
         for iv in eqn.invars:
-            if not isinstance(iv, jcore.Literal):
+            if not is_literal(iv):
                 stack.append(iv)
     return sorted(hits)
 

@@ -76,11 +76,13 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Se
 import numpy as np
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 import jax.numpy as jnp
 
 from . import RuleResult
-from .jaxprutil import TIME, TaintMap, _sub_jaxprs, backward_invars
+from .jaxprutil import (
+    TIME, TaintMap, _sub_jaxprs, backward_invars, is_literal, lit_value,
+)
 
 NEG_INF = float("-inf")
 POS_INF = float("inf")
@@ -337,7 +339,7 @@ class IndexSite(NamedTuple):
 class IntervalMap:
     """Forward interval propagation over a closed jaxpr.
 
-    Same recursion skeleton as jaxprutil.TaintMap: sub-jaxprs (pjit /
+    Same recursion skeleton as jaxprutil.TaintMap: sub-jaxprs (jit /
     cond / while / scan) are entered with operand intervals, `top_eqn`
     names the enclosing top-level equation for witness slicing, and loop
     bodies iterate to a (threshold-widened) fixpoint. `on_eqn(eqn,
@@ -378,7 +380,7 @@ class IntervalMap:
             self.env.setdefault(cv, dtype_range(cv.aval.dtype))
 
     def read(self, atom: Any) -> Iv:
-        if isinstance(atom, jcore.Literal):
+        if is_literal(atom):
             return iv_of_value(atom.val, getattr(atom.aval, "dtype", None))
         got = self.env.get(atom)
         if got is None:
@@ -399,7 +401,7 @@ class IntervalMap:
             self.eqns_seen += 1
             in_ivs = [self.read(v) for v in eqn.invars]
             name = eqn.primitive.name
-            if name == "pjit":
+            if name == "jit":
                 outs = self._run_call(eqn.params["jaxpr"], in_ivs)
             elif name == "cond":
                 outs = self._run_cond(eqn, in_ivs)
@@ -588,10 +590,9 @@ class IntervalMap:
             for x, y, s in ((a, b, sign), (b, a, 1)):
                 if sign == -1 and x is b:
                     continue  # c - x is not affine in x
-                if isinstance(y, jcore.Literal):
-                    c = np.asarray(y.val)
-                    if c.ndim == 0 and c.dtype.kind in "iu":
-                        return self._peel(x), s * int(c)
+                c = lit_value(y)
+                if c is not None:
+                    return self._peel(x), s * c
         return atom, 0
 
     _CMP_OPS = {"lt": "lt", "le": "le", "gt": "gt", "ge": "ge"}
@@ -623,12 +624,9 @@ class IntervalMap:
         if pred_eqn is None or pred_eqn.primitive.name not in self._CMP_OPS:
             return None
         xa, ca = pred_eqn.invars
-        if not isinstance(ca, jcore.Literal):
+        c = lit_value(ca)
+        if c is None:
             return None
-        cval = np.asarray(ca.val)
-        if cval.ndim != 0 or cval.dtype.kind not in "iu":
-            return None
-        c = int(cval)
         base = self._peel(xa)
         x = self.read(base)
         if x.empty or x.poison:

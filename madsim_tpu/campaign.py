@@ -1999,11 +1999,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     s.set_defaults(fn=_cmd_serve)
 
     args = p.parse_args(argv)
-    # persistent XLA cache, same location as the suite/repro CLI: service
-    # restarts and cross-process resumes should pay seconds, not compiles
-    from .repro import _configure_jax_cache
+    # persistent XLA cache: service restarts and cross-process resumes
+    # should pay seconds, not compiles
+    from .compile_cache import configure_compile_cache
 
-    _configure_jax_cache()
+    configure_compile_cache()
     return args.fn(args)
 
 

@@ -1728,6 +1728,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     )
     parser.add_argument("--json", action="store_true", help="JSON line only")
     args = parser.parse_args(argv)
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     wl = _named_workload(args.workload, args.virtual_secs, args.storm)
     shrink_kwargs = {"out_dir": args.out_dir} if args.out_dir else {}

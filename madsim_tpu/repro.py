@@ -69,21 +69,6 @@ def resolve_spec(spec_ref: str, spec_kwargs: Optional[Dict[str, Any]] = None):
     return getattr(mod, fn_name)(**(spec_kwargs or {}))
 
 
-def _configure_jax_cache() -> None:
-    """Persistent XLA cache (same location as the test suite): a repro run
-    in a fresh process should pay seconds, not a cold compile."""
-    try:
-        import jax
-    except ImportError:
-        return
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            f"/tmp/madsim_tpu_jaxcache-{os.getuid()}",
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
-
 def replay_device(
     bundle: ReproBundle,
     spec=None,
@@ -107,7 +92,6 @@ def replay_device(
     depends on (docs/causality.md); when the bundle carries a v3 causal
     digest, the replayed slice's label sha is cross-checked against it
     (schema drift fails loudly, like the config hash)."""
-    _configure_jax_cache()
     import jax
     import numpy as np
 
@@ -407,6 +391,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         "Device replay only.",
     )
     args = p.parse_args(argv)
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     bundle = ReproBundle.load(args.bundle)
     if args.spec_ref:
         bundle.spec_ref = args.spec_ref

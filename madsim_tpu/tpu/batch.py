@@ -335,10 +335,10 @@ def run_batch(
     full final states (the reference's MADSIM_TEST_CHECK_DETERMINISM mode;
     `@batch_test` turns it on from that same env var). The engine is
     deterministic by construction, so this is a tripwire for impure specs
-    and misbehaving backends; note that an execution-caching transport
-    (e.g. a dev tunnel that memoizes identical dispatches) can mask
-    backend-level nondeterminism, though spec-level impurity still bakes
-    in at trace time and is caught.
+    and misbehaving backends. Both runs dispatch the same program on the
+    same inputs, so anything between host and device that memoizes
+    identical dispatches would mask backend-level nondeterminism;
+    spec-level impurity still bakes in at trace time and is caught.
 
     The TPU pass is the seed sweep (runtime/builder.rs:110-148 made wide)
     over ALL visible devices by default (see `resolve_mesh`); the host pass

@@ -1123,6 +1123,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
     parser.add_argument("--quiet", action="store_true")
     args = parser.parse_args(argv)
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     say = (lambda msg: None) if args.quiet else print
     names = list(WORKLOADS) if args.workload == "all" else [args.workload]

@@ -136,16 +136,14 @@ def clean_sharded_segment(mesh):
     """The legal multi-chip refill shape: each device steps its own
     block, no cross-device primitive anywhere (engine._sharded_segment's
     contract, docs/multichip.md)."""
-    from jax.experimental.shard_map import shard_map
-
     P = jax.sharding.PartitionSpec
 
     def seg(x):
         return x * 2 + 1
 
-    return shard_map(
+    return jax.shard_map(
         seg, mesh=mesh, in_specs=(P(mesh.axis_names[0]),),
-        out_specs=P(mesh.axis_names[0]), check_rep=False,
+        out_specs=P(mesh.axis_names[0]), check_vma=False,
     )
 
 
@@ -155,17 +153,15 @@ def leaky_sharded_segment(mesh):
     per-device rows stop being the pure per-seed function the mesh
     bit-identity contract requires. The lane-independence rule's
     collective walk must flag it by exact primitive name."""
-    from jax.experimental.shard_map import shard_map
-
     P = jax.sharding.PartitionSpec
     axis = mesh.axis_names[0]
 
     def seg(x):
         return x + jax.lax.psum(x.sum(), axis)
 
-    return shard_map(
+    return jax.shard_map(
         seg, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -269,11 +265,13 @@ def good_toy_run(hot, cold, const, n=4):
 def leaky_toy_run(hot, cold, const, n=4):
     # const.scale rides the while carry: donation rotates a loop
     # invariant through fresh buffers every segment — the regression the
-    # hot/cold/const split can silently lose
+    # hot/cold/const split can silently lose. The body rewrites s (as a
+    # step that threads the whole state through would): a carry returned
+    # untouched is hoisted out of the loop by jax's while_loop itself.
     def body(carry):
         h, c, s, i = carry
         h2, c2 = good_toy_step(h, c, ToyConst(const.key0, s))
-        return h2, c2, s, i + 1
+        return h2, c2, s + jnp.zeros_like(s), i + 1
 
     def cond(carry):
         return carry[3] < n

@@ -80,7 +80,7 @@ def test_rng_taint_fires_on_trajectory_coupled_schedule_draw():
 
 
 def test_rng_taint_witness_survives_inline_jit():
-    """The mix eqns live inside a pjit sub-jaxpr; the violation must
+    """The mix eqns live inside a jit sub-jaxpr; the violation must
     still fire AND name the offending leaf via the enclosing top-level
     equation."""
     closed = jax.make_jaxpr(toys.impure_draw_inside_jit)(

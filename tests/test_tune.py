@@ -53,8 +53,7 @@ def test_time_scan_ms_warms_the_exact_timed_program():
     """THE node_sharding regression (perf_notes §1-D caveat): run_steps
     jits per (shape, n_steps), so the warmup must run the exact
     (shape, scan) program before any timed rep — and every timed rep
-    must init from a FRESH seed block (the relay caches identical
-    dispatches)."""
+    must init from a FRESH seed block (every timed rep does new work)."""
     calls = []
 
     def init(seeds):

@@ -106,91 +106,6 @@ def bench_tpu(lanes: int, virtual_secs: float, client_rate: float) -> dict:
     }
 
 
-def bench_step_breakdown(lanes: int, virtual_secs: float,
-                         client_rate: float) -> dict:
-    """Where the step time goes: full vs spec-handlers-ablated vs
-    invariants-ablated (VERDICT r3 weak #1 asked for the attribution)."""
-    import dataclasses
-
-    from madsim_tpu.tpu import BatchedSim, make_raft_spec
-    from madsim_tpu.tpu.spec import Outbox
-
-    spec = make_raft_spec(n_nodes=5, client_rate=client_rate, log_capacity=16)
-    cfg = raft_bench_config(virtual_secs)
-
-    def id_on_message(s, nid, src, kind, payload, now, key):
-        E = spec.max_out_msg
-        out = Outbox(
-            valid=jnp.zeros((E,), jnp.bool_),
-            dst=jnp.zeros((E,), jnp.int32),
-            kind=jnp.zeros((E,), jnp.int32),
-            payload=jnp.zeros((E, spec.payload_width), jnp.int32),
-        )
-        return s, out, jnp.int32(-1)
-
-    def id_on_timer(s, nid, now, key):
-        E = spec.max_out
-        out = Outbox(
-            valid=jnp.zeros((E,), jnp.bool_),
-            dst=jnp.zeros((E,), jnp.int32),
-            kind=jnp.zeros((E,), jnp.int32),
-            payload=jnp.zeros((E, spec.payload_width), jnp.int32),
-        )
-        return s, out, now + 50_000
-
-    def id_on_event(s, nid, src, kind, payload, now, key):
-        # fused identity (keeps the ablated variant on the same engine
-        # path / candidate layout as the full fused spec)
-        E = spec.max_out
-        out = Outbox(
-            valid=jnp.zeros((E,), jnp.bool_),
-            dst=jnp.zeros((E,), jnp.int32),
-            kind=jnp.zeros((E,), jnp.int32),
-            payload=jnp.zeros((E, spec.payload_width), jnp.int32),
-        )
-        return s, out, jnp.where(kind == -1, now + 50_000, jnp.int32(-1))
-
-    # the ablated trio is internally consistent (same identity behavior);
-    # the stale-wrapper guard requires the derivation to be visible
-    id_on_message.__wraps_event__ = id_on_event
-    id_on_timer.__wraps_event__ = id_on_event
-
-    variants = {
-        "full": BatchedSim(spec, cfg),
-        "no_handlers": BatchedSim(
-            dataclasses.replace(
-                spec, on_message=id_on_message, on_timer=id_on_timer,
-                on_event=id_on_event,
-            ),
-            cfg,
-        ),
-        "no_invariants": BatchedSim(
-            dataclasses.replace(
-                spec, check_invariants=lambda ns, alive, now: jnp.bool_(True)
-            ),
-            cfg,
-        ),
-    }
-    from madsim_tpu.measure import time_scan_ms
-
-    SCAN = 300
-    out = {}
-    for name, sim in variants.items():
-        # the shared scan-on-device discipline: fresh seeds per rep,
-        # the exact (shape, SCAN) program warmed before timing
-        out[name] = round(
-            time_scan_ms(
-                sim.init, sim.run_steps, lanes, scan=SCAN, warm_steps=200
-            ),
-            3,
-        )
-    return {
-        "step_ms_full": out["full"],
-        "step_ms_spec_handlers": round(out["full"] - out["no_handlers"], 3),
-        "step_ms_invariant_check": round(out["full"] - out["no_invariants"], 3),
-    }
-
-
 def bench_buggify_ab(lanes: int, virtual_secs: float) -> dict:
     """A/B: the heavy-tail delay buggify (net/mod.rs:287-295 analog) on the
     KV linearizability fuzz — extreme stragglers are a distinct bug class,
@@ -733,10 +648,6 @@ def main() -> None:
     paxos = bench_paxos(args.lanes // 4, args.virtual_secs)
     chain = bench_chain(args.lanes // 4, args.virtual_secs)
     buggify = bench_buggify_ab(args.lanes // 16, args.virtual_secs)
-    breakdown = (
-        {} if args.skip_breakdown
-        else bench_step_breakdown(args.lanes, args.virtual_secs, args.client_rate)
-    )
     roofline = (
         {} if args.skip_breakdown
         else bench_roofline(args.lanes, args.virtual_secs, args.client_rate)
@@ -831,7 +742,6 @@ def main() -> None:
         ),
         # heavy-tail buggify A/B (events explored with/without the tail)
         "buggify_ab": buggify,
-        **breakdown,
         **roofline,
         # time-to-first-bug (the metric's other half): wall-clock from a
         # cold runtime to a confirmed violating seed and to a finished

@@ -151,7 +151,7 @@ def smoke_one(name: str, wl) -> dict:
         "violations": res.violations,
         "overflow": int(res.summary["total_overflow"]),
         "dispatches": res.dispatches,
-        "device_ms": round(res.device_ms, 1),
+        "wall_ms": round(res.wall_ms, 1),
         "wall_s": round(wall, 2),  # informational ONLY — never asserted
         "events": int(res.summary["total_events"]),
     }

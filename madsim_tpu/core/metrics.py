@@ -76,7 +76,7 @@ class RuntimeMetrics:
     @property
     def device_ms(self) -> float:
         """Wall-clock ms spent inside the executor's run loop (task
-        polls, not time-wheel bookkeeping) — what `BatchResult.device_ms`
+        polls, not time-wheel bookkeeping) — what `BatchResult.wall_ms`
         reports for a device sweep."""
         return self._executor.loop_busy_s * 1e3
 

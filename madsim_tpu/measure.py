@@ -18,9 +18,11 @@ Four rules, each on its own merits:
     the host's launch latency, which would drown a step of device work.
 
 This module is the single implementation: `bench.py`,
-`benches/ablate_step.py`, `benches/node_sharding.py` (via the
-`benches/measure.py` shim) and the `madsim_tpu.tune` autotuner all
-measure through it. Wall clocks here are `time.perf_counter` only —
+`benches/node_sharding.py` (via the `benches/measure.py` shim) and the
+`madsim_tpu.tune` autotuner all measure through it. It times whole
+programs; the split of a step by phase comes from a device trace of the
+step as it runs, whose ops carry its named phases
+(`engine.STEP_PHASES`). Wall clocks here are `time.perf_counter` only —
 measurement clocks never feed simulation state, so the module meets the
 ambient-entropy lint bar with zero pragmas.
 """

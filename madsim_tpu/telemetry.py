@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import json
 import os
 import sys
@@ -162,7 +163,7 @@ class Gauge(_Instrument):
 
 
 class Histogram(_Instrument):
-    """Bucketed distribution (span durations, device_ms...)."""
+    """Bucketed distribution (span durations, causal depths...)."""
 
     kind = "histogram"
 
@@ -295,6 +296,8 @@ class MetricsRegistry:
             "labels": {k: str(v) for k, v in sorted(rec.labels.items())},
             "seq": seq,
             "thread": rec.thread,
+            "id": rec.span_id,
+            "parent": rec.parent_id,
         })
 
     # ----------------------------------------------------------- textfile
@@ -401,6 +404,13 @@ def read_events(path: str) -> List[Dict[str, Any]]:
 # --------------------------------------------------------------------------
 
 
+def span_label(name: str, labels: Dict[str, Any]) -> str:
+    """``name[site]`` (or ``name`` without a site label): a span's name in
+    a profile, and the label the benchmark harness gives it."""
+    site = labels.get("site", "")
+    return f"{name}[{site}]" if site else name
+
+
 @dataclasses.dataclass
 class SpanRecord:
     name: str
@@ -408,6 +418,14 @@ class SpanRecord:
     dur_s: float
     thread: str
     labels: Dict[str, Any]
+    # the span tree: this span's id, and the id of the span that was open
+    # on the same thread when it opened (None for a root)
+    span_id: int = 0
+    parent_id: Optional[int] = None
+
+    @property
+    def label(self) -> str:
+        return span_label(self.name, self.labels)
 
 
 class _TelemetryState:
@@ -474,32 +492,72 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **labels: Any) -> None:
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
 
+# span ids (process-wide) and each thread's stack of open spans: the
+# parent of a span is the innermost span open on its thread
+_SPAN_IDS = itertools.count(1)
+_OPEN = threading.local()
+
+
+def _open_stack() -> List[int]:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
 
 class _Span:
-    __slots__ = ("name", "labels", "_t0")
+    __slots__ = ("name", "labels", "_t0", "_id", "_parent", "_annotation")
 
     def __init__(self, name: str, labels: Dict[str, Any]) -> None:
         self.name = name
         self.labels = labels
 
+    @property
+    def label(self) -> str:
+        return span_label(self.name, self.labels)
+
+    def set(self, **labels: Any) -> None:
+        """Record what the span did (bytes fetched, events decoded...):
+        the labels land on its SpanRecord when it closes."""
+        self.labels.update(labels)
+
     def __enter__(self):
+        # the same span on the profiler's clock: under jax.profiler it
+        # lands on the host plane as `name[site]`, nested like the spans
+        from jax.profiler import TraceAnnotation
+
+        stack = _open_stack()
+        self._id = next(_SPAN_IDS)
+        self._parent = stack[-1] if stack else None
+        stack.append(self._id)
+        self._annotation = TraceAnnotation(self.label)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        stack = _open_stack()
+        if stack and stack[-1] == self._id:
+            stack.pop()
         st = _STATE
         if not st.enabled:
             return False
-        t1 = time.perf_counter()
         rec = SpanRecord(
             name=self.name,
             t0_s=self._t0 - st.t0,
             dur_s=t1 - self._t0,
             thread=threading.current_thread().name,
             labels=self.labels,
+            span_id=self._id,
+            parent_id=self._parent,
         )
         with st.lock:
             if len(st.spans) < MAX_SPANS:
@@ -509,8 +567,9 @@ class _Span:
         reg = st.registry
         if reg is not None:
             reg.histogram(
-                "span_seconds", "wall-clock span durations by site"
-            ).observe(rec.dur_s, site=self.name)
+                "span_seconds", "wall-clock span durations by span and site"
+            ).observe(rec.dur_s, span=self.name,
+                      site=self.labels.get("site", ""))
             reg.span_event(rec)
         return False
 
@@ -522,7 +581,10 @@ def span(name: str, **labels: Any):
     slice — wrap their host-side bodies in this. Spans never run inside
     jitted code and never touch simulation state; they only read the
     monotonic clock (`time.perf_counter`, allowlisted by the
-    ambient-entropy lint) and append to a host-side list.
+    ambient-entropy lint) and append to a host-side list. Each span
+    records its parent (the span open on the same thread when it
+    opened), takes counts through ``.set(**labels)`` before it closes,
+    and shows in a `jax.profiler` trace as a ``name[site]`` annotation.
     """
     if not _STATE.enabled:
         return _NOOP_SPAN
@@ -532,6 +594,23 @@ def span(name: str, **labels: Any):
 def spans() -> List[SpanRecord]:
     with _STATE.lock:
         return list(_STATE.spans)
+
+
+def self_s(span: SpanRecord, spans: Sequence[SpanRecord]) -> float:
+    """A span's self time: its duration minus the part of its interval
+    that its children (the spans whose parent it is) cover."""
+    lo, hi = span.t0_s, span.t0_s + span.dur_s
+    kids = sorted(
+        (max(c.t0_s, lo), min(c.t0_s + c.dur_s, hi))
+        for c in spans if c.parent_id == span.span_id
+    )
+    covered, end = 0.0, lo
+    for a, b in kids:
+        a = max(a, end)
+        if b > a:
+            covered += b - a
+            end = b
+    return span.dur_s - covered
 
 
 # --------------------------------------------------------------------------
@@ -552,9 +631,9 @@ def record_summary(summary: Dict[str, Any], **labels: Any) -> None:
             reg.counter(f"sweep_{key}", f"sweep {key} total").inc(
                 int(summary[key]), **labels
             )
-    if "device_ms" in summary:
-        reg.counter("sweep_device_ms", "sweep wall ms (dispatch→decode)") \
-            .inc(float(summary["device_ms"]), **labels)
+    if "wall_ms" in summary:
+        reg.counter("sweep_wall_ms", "sweep wall ms (dispatch→decode)") \
+            .inc(float(summary["wall_ms"]), **labels)
     for key in ("occupancy", "coverage_bits", "first_violation_step"):
         if key in summary and isinstance(summary[key], (int, float)):
             reg.gauge(f"sweep_{key}", f"sweep {key}").set(
@@ -576,7 +655,7 @@ def record_summary(summary: Dict[str, Any], **labels: Any) -> None:
 
 def record_batch_result(result, **labels: Any) -> None:
     """BatchResult → registry (summary scalars ride through
-    record_summary; occupancy/dispatches/device_ms are summary keys)."""
+    record_summary; occupancy/dispatches/wall_ms are summary keys)."""
     if _STATE.registry is None:
         return
     record_summary(result.summary, **labels)

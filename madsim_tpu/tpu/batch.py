@@ -181,12 +181,13 @@ class BatchResult:
     # sweep-overhead visibility without running benches: how many device
     # program launches the sweep itself cost (init + run segments +
     # sharding puts, via BatchedSim.dispatch_count — excludes post-sweep
-    # traces/shrinks), and the sweep loop's wall time in ms (dispatch
-    # through readback of the last chunk). The dispatch-budget regression
-    # test pins `dispatches` so eager-init-style regressions (r5's
-    # ~1.4 s/sweep of per-op dispatch latency) can't silently return.
+    # traces/shrinks), and the sweep loop's host wall time in ms (dispatch
+    # through readback of the last chunk; not device time). The
+    # dispatch-budget regression test pins `dispatches` so eager-init-style
+    # regressions (r5's ~1.4 s/sweep of per-op dispatch latency) can't
+    # silently return.
     dispatches: int = 0
-    device_ms: float = 0.0
+    wall_ms: float = 0.0
     # -- continuous batching (r9, docs/continuous_batching.md) --
     # lane occupancy: busy-lane-steps / total-lane-steps over the sweep.
     # Exact on the refill path (engine counters); on the chunked path an
@@ -477,17 +478,40 @@ def run_batch(
         )
     if dispatch_steps is None:
         dispatch_steps = DEFAULT_DISPATCH_STEPS
+    common = dict(
+        chunk=chunk, mesh=resolve_mesh(mesh), pipeline=pipeline,
+        coverage=coverage, check_determinism=check_determinism,
+        repro_on_host=repro_on_host, max_host_repros=max_host_repros,
+        max_traces=max_traces, shrink_on_violation=shrink_on_violation,
+        shrink_kwargs=shrink_kwargs, dispatch_steps=dispatch_steps,
+    )
     if refill:
-        return _run_batch_refill(
-            seeds_arr, workload, sim, int(refill), chunk=chunk,
-            mesh=resolve_mesh(mesh),
-            pipeline=pipeline, coverage=coverage,
-            check_determinism=check_determinism,
-            repro_on_host=repro_on_host, max_host_repros=max_host_repros,
-            max_traces=max_traces, shrink_on_violation=shrink_on_violation,
-            shrink_kwargs=shrink_kwargs, dispatch_steps=dispatch_steps,
-        )
-    mesh = resolve_mesh(mesh)
+        with telemetry.span("run_batch", site="refill"):
+            return _run_batch_refill(
+                seeds_arr, workload, sim, int(refill), **common
+            )
+    with telemetry.span("run_batch", site="chunked"):
+        return _run_batch_chunked(seeds_arr, workload, sim, **common)
+
+
+def _run_batch_chunked(
+    seeds_arr: np.ndarray,
+    workload: BatchWorkload,
+    sim: BatchedSim,
+    chunk: int,
+    mesh: Optional[Any],
+    pipeline: bool,
+    coverage: bool,
+    check_determinism: bool,
+    repro_on_host: bool,
+    max_host_repros: int,
+    max_traces: int,
+    shrink_on_violation: bool,
+    shrink_kwargs: Optional[Dict[str, Any]],
+    dispatch_steps: int,
+) -> BatchResult:
+    """run_batch's chunked sweep: each `chunk` of seeds is one `sim.run`
+    over its own lanes, chunk k+1 dispatched before chunk k is decoded."""
     n_dev = int(mesh.devices.size) if mesh is not None else 1
 
     violated_parts: List[np.ndarray] = []
@@ -539,6 +563,8 @@ def run_batch(
     def _decode(entry) -> None:
         nonlocal state
         off, size, pad, st, rerun = entry
+        with telemetry.span("wait", site="run_batch"):
+            jax.block_until_ready(st)
         if rerun is not None:
             _assert_runs_bitwise_equal(
                 st, rerun, f"seeds[{off}:{off + size}]"
@@ -569,7 +595,9 @@ def run_batch(
             clean = np.nonzero(~violated_parts[-1])[0][: workload.lane_check_sample]
             picked = np.concatenate([v, clean])
             if picked.size:
-                for k2, v2 in workload.lane_check(st, picked).items():
+                with telemetry.span("lane_check", site="run_batch"):
+                    checked = workload.lane_check(st, picked)
+                for k2, v2 in checked.items():
                     if isinstance(v2, (int, np.integer)):
                         s["lane_check_" + k2] = int(v2)
         for k, v in s.items():
@@ -614,7 +642,7 @@ def run_batch(
     if enabled_fire_kinds(sim.config):
         totals["chaos_coverage"] = coverage_report(totals, sim.config)
     totals["dispatches"] = sweep_dispatches
-    totals["device_ms"] = round(sweep_ms, 3)
+    totals["wall_ms"] = round(sweep_ms, 3)
     cov = None
     if coverage:
         cov = LaneCoverage(
@@ -640,7 +668,7 @@ def run_batch(
         workload=workload,
         coverage=cov,
         dispatches=sweep_dispatches,
-        device_ms=sweep_ms,
+        wall_ms=sweep_ms,
         occupancy=occupancy,
         retired_step=np.concatenate(steps_parts),
         violation_step=np.concatenate(vstep_parts),
@@ -671,7 +699,8 @@ def _post_sweep(
         # A triage failure must never eat the primary result — which seeds
         # violated — so it degrades to a warning and the normal report.
         try:
-            result.shrink(**(shrink_kwargs or {}))
+            with telemetry.span("shrink", site="run_batch"):
+                result.shrink(**(shrink_kwargs or {}))
         except Exception as e:  # noqa: BLE001 - opt-in convenience step
             import warnings
 
@@ -789,6 +818,8 @@ def _run_batch_refill(
     def _decode(entry) -> None:
         nonlocal state, occ_num, occ_den
         off, size, st, rerun = entry
+        with telemetry.span("wait", site="run_batch_refill"):
+            jax.block_until_ready(st)
         if rerun is not None:
             _assert_runs_bitwise_equal(
                 st, rerun, f"seeds[{off}:{off + size}] (refill)"
@@ -846,7 +877,7 @@ def _run_batch_refill(
     if enabled_fire_kinds(sim.config):
         totals["chaos_coverage"] = coverage_report(totals, sim.config)
     totals["dispatches"] = sweep_dispatches
-    totals["device_ms"] = round(sweep_ms, 3)
+    totals["wall_ms"] = round(sweep_ms, 3)
     cov = None
     if coverage:
         cov = LaneCoverage(
@@ -870,7 +901,7 @@ def _run_batch_refill(
         workload=workload,
         coverage=cov,
         dispatches=sweep_dispatches,
-        device_ms=sweep_ms,
+        wall_ms=sweep_ms,
         occupancy=occupancy,
         retired_step=np.concatenate([r["retired"] for r in res_parts]),
         violation_step=np.concatenate(

@@ -64,12 +64,14 @@ batch, any chunk, any mesh sharding.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from . import bitpack, prng
 from .spec import (
     EID_NONE,
@@ -146,6 +148,35 @@ COV_SALT = 0x5EEDC0DE  # base key of the event-class hash chain
 # one face without the other (and without updating this tuple) is the
 # silent mirror break that desyncs every recorded cov_digest downstream.
 COV_FIELDS = ("node", "src", "kind", "bucket")
+
+# the step's phases, in the order its sections run: each is a named
+# scope (`step/<phase>`) over a group of _step_traced's numbered sections
+# — select 0-3b, handlers 4, chaos 5-5e and 6b, network 6, invariants 7,
+# finish 7b-10 (with the repack of the packed planes)
+STEP_PHASES = ("select", "handlers", "chaos", "network", "invariants",
+               "finish")
+
+
+class _Phases:
+    """The step's phase marker: `phase(name)` closes the open phase scope
+    and opens the next, all nested under one outer scope; `close()` ends
+    both. Scopes change op metadata only — the jaxpr, the compiled
+    arithmetic and every trajectory stay exactly as they are."""
+
+    def __init__(self, outer: str) -> None:
+        self._outer = contextlib.ExitStack()
+        self._outer.enter_context(jax.named_scope(outer))
+        self._open = contextlib.ExitStack()
+
+    def __call__(self, name: str) -> None:
+        assert name in STEP_PHASES, name
+        self._open.close()
+        self._open.enter_context(jax.named_scope(name))
+
+    def close(self) -> None:
+        self._open.close()
+        self._outer.close()
+
 
 # the sweep segment length: how many steps one device dispatch covers.
 # ONE definition — run_batch, the autotuner's default assignment and the
@@ -1871,7 +1902,7 @@ class BatchedSim:
     # ------------------------------------------------------------------ step
 
     def _step(self, state: SimState) -> SimState:
-        return self._step_traced(state)[0]
+        return self._step_scoped(state)[0]
 
     def _step_split(self, hot: SimState, cold: ColdState, const: ConstState):
         """One step in the sweep loop's (hot, cold | const) form: const is
@@ -1879,15 +1910,28 @@ class BatchedSim:
         loop body reads key0/ctl/skew_ppm but never re-emits them. This is
         the program benches/roofline.py accounts bytes for (the step the
         sweep actually runs); merge/split are free pytree restructuring."""
-        s2, rec = self._step_traced(merge_state(hot, cold, const))
+        s2, rec = self._step_scoped(merge_state(hot, cold, const))
         h2, c2, _ = split_state(s2)
         return h2, c2, rec
 
-    def _step_traced(self, state: SimState) -> Tuple[SimState, TraceRecord]:
+    def _step_scoped(self, state: SimState) -> Tuple[SimState, TraceRecord]:
+        """`_step_traced` with every op under the named scope
+        `step/<phase>`: one phase per group of its numbered sections
+        (`STEP_PHASES`), so a device trace splits the step by phase."""
+        phase = _Phases("step")
+        try:
+            return self._step_traced(state, phase)
+        finally:
+            phase.close()
+
+    def _step_traced(
+        self, state: SimState, phase: "_Phases"
+    ) -> Tuple[SimState, TraceRecord]:
         """One engine step + the step's TraceRecord.
 
         Untraced callers discard the record; XLA dead-code-eliminates its
-        construction, so the trace costs nothing unless collected."""
+        construction, so the trace costs nothing unless collected.
+        `phase(name)` opens each section group's named scope."""
         spec, cfg = self.spec, self.config
         N, CK, P = spec.n_nodes, self._CK, spec.payload_width
         L = state.clock.shape[0]
@@ -1895,6 +1939,7 @@ class BatchedSim:
         strag: Optional[StragPool] = state.strag
         narange = jnp.arange(N, dtype=jnp.int32)
 
+        phase("select")
         # -- 0. unpack the compacted carry (r8, docs/state_layout.md):
         # bit-packed bool planes -> bool tensors, narrow node leaves ->
         # i32. Pure elementwise shifts/converts that fuse into the step;
@@ -2105,6 +2150,7 @@ class BatchedSim:
             evt_eid_full = None
             tr_lam = tr_evt_eid = tr_sent_eid = None
 
+        phase("handlers")
         # -- 4. run handlers + fused state select. The three masks are
         # pairwise DISJOINT: at most one event per node per step (msg vs
         # timer), and a restarting node was dead all step (dead nodes'
@@ -2279,6 +2325,7 @@ class BatchedSim:
             state.clock,
         )
 
+        phase("chaos")
         # -- 5. crash/restart chaos (Handle::kill/restart analog) ----------
         # (`alive` was unpacked from the carry at step 0)
         crashed, chaos_at = state.crashed, state.chaos_at
@@ -2781,6 +2828,7 @@ class BatchedSim:
                 reset = reset | join_mask
             new_dur = _tree_where(reset, self._dur_of(node), dur_mid)
 
+        phase("network")
         # -- 6. collect outboxes, roll the network, pack into pool ---------
         def flat(out: Outbox, emitting, e):  # [L,N,e,...] -> [L, N*e, ...]
             v = (out.valid & emitting[:, :, None]).reshape(L, N * e)
@@ -3139,6 +3187,7 @@ class BatchedSim:
         else:
             new_strag = None
 
+        phase("chaos")
         # -- 6b. chaos fire counts (the coverage report's raw data) --------
         # every enabled clause must show nonzero fires over a seed batch;
         # an enabled clause with zero fires is dead chaos (nemesis.py)
@@ -3211,6 +3260,7 @@ class BatchedSim:
                 _occ_mark(OCC_ROW["disk"], ap_dslow, state.nem.disk_k)
             occ_fired = jnp.stack(ocols, axis=1)
 
+        phase("invariants")
         # -- 7. invariants + lane lifecycle --------------------------------
         ok = self._v_check(node, alive, clock)
         new_violation = active & ~ok & ~state.violated
@@ -3237,6 +3287,7 @@ class BatchedSim:
             )
         done = state.done | deadlocked | reached_horizon | violated
 
+        phase("finish")
         # -- 7b. coverage accumulation (BatchedSim(coverage=True) only) ----
         # One bit per exercised event class: hash(dst node, src, msg kind,
         # payload[0] magnitude bucket) for deliveries, hash(node, -1, -1, 0)
@@ -4570,8 +4621,11 @@ class BatchedSim:
             # block on the reduction only AFTER the next segment is in
             # flight: the early stop costs at most one no-op segment,
             # never a device-idle host round-trip
-            if alive is not None and not bool(alive):
-                break
+            if alive is not None:
+                with telemetry.span("wait", site="segment"):
+                    stop = not bool(alive)
+                if stop:
+                    break
             if alive is None and remaining > 0:
                 alive = True  # arm the check from the second segment on
         return state

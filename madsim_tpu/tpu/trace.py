@@ -18,10 +18,12 @@ that violated, bit-identical to the lane inside the original batch.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
+from .. import telemetry
 from .engine import BatchedSim, TraceRecord
 
 
@@ -107,6 +109,14 @@ def extract_trace(
     Steps after the lane finished record no events (active lanes only), so
     the list self-truncates at the violation/horizon.
     """
+    return _extract(recs, kind_names, lane)[0]
+
+
+def _extract(
+    recs: TraceRecord, kind_names: Optional[Sequence[str]], lane: int,
+) -> Tuple[List[TraceEvent], int]:
+    """extract_trace's events, and how many steps with activity it
+    visited."""
     # times are (epoch, offset) pairs — combine to absolute int64 us
     # (spec.REBASE_US; the record's offsets are post-rebase, so a step that
     # rebased reports its events in the NEW basis consistently)
@@ -158,7 +168,8 @@ def extract_trace(
         | (remove >= 0) | (join >= 0)
         | (disk_slow >= 0) | (disk_crash >= 0) | (disk_recover >= 0)
     )
-    for t in np.nonzero(busy)[0]:
+    busy_steps = np.nonzero(busy)[0]
+    for t in busy_steps:
         t = int(t)
         # chaos fires at the window start t_next == min(t_evt) (inactive
         # nodes default to it); violation/deadlock are end-of-step facts and
@@ -287,7 +298,7 @@ def extract_trace(
     # later-time in-window event; a stable time sort restores the
     # chronological contract (per-node and same-instant orders preserved)
     events.sort(key=lambda e: e.t_us)
-    return events
+    return events, int(busy_steps.size)
 
 
 def format_trace(events: Sequence[TraceEvent]) -> str:
@@ -305,6 +316,20 @@ def trace_seed(
 
     `ctl` (a single-lane TriageCtl; triage-mode sims only) traces a shrunk
     candidate — suppressed clauses/occurrences never appear in the events.
+
+    Three spans split the call: `scan[trace]` (the traced scan, to its
+    last record), `fetch[trace]` (one transfer of the whole record to the
+    host; labelled with its `bytes`) and `extract[trace]` (the host
+    decode; labelled with the active `steps` it visited and the `events`
+    it returned).
     """
-    _, recs = sim.run_traced(seed, max_steps=max_steps, ctl=ctl)
-    return extract_trace(recs, kind_names=kind_names)
+    with telemetry.span("scan", site="trace"):
+        _, recs = sim.run_traced(seed, max_steps=max_steps, ctl=ctl)
+        jax.block_until_ready(recs)
+    with telemetry.span("fetch", site="trace") as sp:
+        recs = jax.device_get(recs)
+        sp.set(bytes=sum(x.nbytes for x in jax.tree_util.tree_leaves(recs)))
+    with telemetry.span("extract", site="trace") as sp:
+        events, steps = _extract(recs, kind_names, lane=0)
+        sp.set(steps=steps, events=len(events))
+    return events

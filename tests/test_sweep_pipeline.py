@@ -74,7 +74,7 @@ def test_donated_run_end_to_end_deterministic():
 
 
 def _strip_timing(summary):
-    return {k: v for k, v in summary.items() if k != "device_ms"}
+    return {k: v for k, v in summary.items() if k != "wall_ms"}
 
 
 def test_pipelined_run_batch_bit_identical_to_serial():
@@ -142,7 +142,7 @@ def test_dispatch_budget_single_chunk():
     )
     assert res.dispatches == 2, res.dispatches
     assert res.summary["dispatches"] == 2
-    assert res.device_ms > 0
+    assert res.wall_ms > 0
 
 
 def test_dispatch_budget_chunked():

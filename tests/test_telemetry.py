@@ -245,10 +245,10 @@ def test_spans_capture_threads_and_export_wellformed_perfetto(tmp_path):
     assert sorted(r.name for r in recs) == ["dispatch", "slice"]
     assert {r.thread for r in recs} == {"MainThread", "lane-1"}
     assert all(r.dur_s > 0 and r.t0_s >= 0 for r in recs)
-    # the registry histogram sees every span, labeled by site
+    # the registry histogram sees every span, labeled by span and site
     h = telemetry.get_registry().histogram("span_seconds")
-    assert h.snapshot(site="dispatch")["count"] == 1
-    assert h.snapshot(site="slice")["count"] == 1
+    assert h.snapshot(span="dispatch", site="")["count"] == 1
+    assert h.snapshot(span="slice", site="")["count"] == 1
 
     path = str(tmp_path / "loop.perfetto.json")
     telemetry.write_spans_perfetto(path)
@@ -567,7 +567,7 @@ def test_perfetto_lineage_flow_on_real_trace():
 @pytest.mark.chaos
 def test_run_batch_routes_metrics_and_writes_timeline(violating_sweep):
     """With telemetry enabled, run_batch emits the sweep's summary through
-    the registry (violations, occupancy, dispatches, device_ms, chaos
+    the registry (violations, occupancy, dispatches, wall_ms, chaos
     fires per clause AND per occurrence) and drops the traced violation's
     timeline next to the events stream — all post-sweep, observe-only."""
     wl, result, tdir = violating_sweep
@@ -591,7 +591,7 @@ def test_run_batch_routes_metrics_and_writes_timeline(violating_sweep):
     assert sum(
         e["value"] for e in by_name["sweep_dispatches"]
     ) == result.dispatches
-    assert "sweep_device_ms" in by_name and "sweep_occupancy" in by_name
+    assert "sweep_wall_ms" in by_name and "sweep_occupancy" in by_name
     # chaos fires per clause and per occurrence rode through
     fire_clauses = {
         e["labels"]["clause"] for e in by_name.get("chaos_fires", [])

@@ -156,6 +156,30 @@ COV_FIELDS = ("node", "src", "kind", "bucket")
 STEP_PHASES = ("select", "handlers", "chaos", "network", "invariants",
                "finish")
 
+# chain turns per iteration of `_turn_key`'s loop
+_KEY_TURNS = 128
+
+
+def _chain_key(key: jnp.ndarray) -> jnp.ndarray:
+    """One turn of the per-lane hash-chain key: the step's section 2. Every
+    step turns it, a done lane's too, so the key is the one leaf of a done
+    lane's state that still changes."""
+    return prng.fold(key, 1)
+
+
+def _turn_key(key: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
+    """`key` after `n` (a traced i32) more turns of `_chain_key`."""
+
+    def turns(_, k):
+        for _ in range(_KEY_TURNS):
+            k = _chain_key(k)
+        return k
+
+    key = jax.lax.fori_loop(0, n // _KEY_TURNS, turns, key)
+    return jax.lax.fori_loop(
+        0, n % _KEY_TURNS, lambda _, k: _chain_key(k), key
+    )
+
 
 class _Phases:
     """The step's phase marker: `phase(name)` closes the open phase scope
@@ -625,7 +649,7 @@ class TraceRecord(NamedTuple):
     restart: Any  # i32 [L] node restarted this step, -1 = none
     split: Any  # bool [L] partition split happened this step
     heal: Any  # bool [L] partition healed this step
-    side_mask: Any  # i32 [L] bitmask of nodes on side A after a split
+    side_mask: Any  # i32 [L] bitmask of nodes on side A after a split, else 0
     violation: Any  # bool [L] invariant first violated this step
     deadlock: Any  # bool [L]
     clog_src: Any  # i32 [L] link clogged src this step, -1 = none
@@ -2014,7 +2038,7 @@ class BatchedSim:
             w_end = jnp.where(chaos_in_w, t_next, w_end)
 
         # -- 2. advance per-lane keys (cheap hash chain, see prng.py) ------
-        key = prng.fold(state.key, 1)
+        key = _chain_key(state.key)
         node_key = prng.fold(key[:, None], jnp.arange(N, dtype=jnp.uint32))  # [L,N]
         mkeys = prng.fold(node_key, 101)
         tkeys = prng.fold(node_key, 102)
@@ -2471,9 +2495,11 @@ class BatchedSim:
             )
             partitioned = (state.partitioned | do_split) & ~do_heal
             tr_split, tr_heal = ap_split, ap_heal
-            tr_side = (
+            # the sides on the split's own step only: the legacy path draws
+            # `side` from the chain key every step, done lanes' included
+            tr_side = jnp.where(tr_split, (
                 side.astype(jnp.int32) * (1 << jnp.arange(N, dtype=jnp.int32))
-            ).sum(-1)
+            ).sum(-1), 0)
 
         # -- 5c. nemesis link-clog + latency-spike windows ------------------
         # (toggle machinery like crash/partition, schedule-timed; the clog
@@ -4650,25 +4676,60 @@ class BatchedSim:
         jax.jit, static_argnums=(0, 2), donate_argnums=(1,)
     )
     def _run_traced(self, state: SimState, n_steps: int):
+        """`n_steps` traced steps, bit for bit a fixed-length `lax.scan` of
+        the step: the final state and the [n_steps, L, ...] records. The
+        loop stops one step after every lane is done. A done lane's state
+        is a fixed point of the step but for its chain key, which no record
+        leaf reads, so that step's record is the record of every later
+        step: it fills the rows left, and the key takes its turns alone."""
         hot, cold, const = split_state(state)
+        rec = jax.eval_shape(lambda: self._step_split(hot, cold, const)[2])
+        recs = jax.tree_util.tree_map(
+            lambda x: jnp.zeros((n_steps,) + x.shape, x.dtype), rec
+        )
 
-        def body(carry, _):
-            h, c = carry
+        def cond(carry):
+            _h, _c, i, _r, frozen = carry
+            return (i < n_steps) & ~frozen
+
+        def body(carry):
+            h, c, i, r, _ = carry
             h2, c2, rec = self._step_split(h, c, const)
-            return (h2, c2), rec
+            r = jax.tree_util.tree_map(
+                lambda buf, x: jax.lax.dynamic_update_index_in_dim(
+                    buf, x, i, 0
+                ),
+                r, rec,
+            )
+            return h2, c2, i + 1, r, jnp.all(h.done)
 
-        (h, c), recs = jax.lax.scan(body, (hot, cold), None, length=n_steps)
+        h, c, i, recs, _ = jax.lax.while_loop(
+            cond, body, (hot, cold, jnp.int32(0), recs, jnp.bool_(False))
+        )
+        after = jnp.arange(n_steps) >= i  # rows the loop did not step
+
+        def fill(buf):
+            last = jax.lax.dynamic_index_in_dim(buf, i - 1, 0)
+            return jnp.where(
+                after.reshape((n_steps,) + (1,) * (buf.ndim - 1)), last, buf
+            )
+
+        recs = jax.tree_util.tree_map(fill, recs)
+        h = h._replace(key=_turn_key(h.key, n_steps - i))
         return merge_state(h, c, const), recs
 
     def run_traced(self, seed: int, max_steps: int = 20_000, ctl=None):
         """Re-run ONE seed with full event capture (the violation microscope).
 
-        Returns (final_state, TraceRecord with [T, 1, ...] leaves). Use
-        trace.extract_trace to turn the records into readable events. The
-        trajectory is bit-identical to the same seed inside any batch: the
-        step function is the same jitted program and all randomness is
-        derived from the lane seed, never from lane position. `ctl` (triage
-        mode) traces a SHRUNK candidate — e.g. a repro bundle's — with the
+        Returns (final_state, TraceRecord with [T, 1, ...] leaves),
+        T = `max_steps`. Use trace.extract_trace to turn the records into
+        readable events. The trajectory is bit-identical to the same seed
+        inside any batch: the step function is the same jitted program and
+        all randomness is derived from the lane seed, never from lane
+        position. The device stops stepping one step after the lane is
+        done; the rows past it hold the done lane's record, as if it had
+        been stepped to `max_steps` (`_run_traced`). `ctl` (triage mode)
+        traces a SHRUNK candidate — e.g. a repro bundle's — with the
         suppressed faults absent from the record stream.
         """
         seeds = jnp.asarray([seed], jnp.uint32)

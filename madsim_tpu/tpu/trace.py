@@ -8,7 +8,9 @@ captured TraceRecord stream as a readable event log — every message
 delivery (src→dst, kind, payload), timer fire, crash/restart and partition
 split/heal, stamped with step index and virtual time, ending at the exact
 step the invariant broke. No host twin needed: the trace IS the trajectory
-that violated, bit-identical to the lane inside the original batch.
+that violated, bit-identical to the lane inside the original batch. The
+device stops stepping one step after the lane is done; the record keeps
+all `max_steps` rows, those past the stop repeating the done lane's.
 
     state, recs = sim.run_traced(bad_seed)
     events = extract_trace(recs, kind_names=["REQUEST_VOTE", ...])
@@ -318,14 +320,22 @@ def trace_seed(
     candidate — suppressed clauses/occurrences never appear in the events.
 
     Three spans split the call: `scan[trace]` (the traced scan, to its
-    last record), `fetch[trace]` (one transfer of the whole record to the
-    host; labelled with its `bytes`) and `extract[trace]` (the host
-    decode; labelled with the active `steps` it visited and the `events`
-    it returned).
+    last record; labelled with the `steps` the lane ran before it was done
+    and the `max_steps` of its record, since the scan stops with the
+    lane), `fetch[trace]` (one transfer of the whole record to the host;
+    labelled with its `bytes`) and `extract[trace]` (the host decode;
+    labelled with the active `steps` it visited and the `events` it
+    returned).
     """
-    with telemetry.span("scan", site="trace"):
-        _, recs = sim.run_traced(seed, max_steps=max_steps, ctl=ctl)
+    with telemetry.span("scan", site="trace") as sp:
+        state, recs = sim.run_traced(seed, max_steps=max_steps, ctl=ctl)
         jax.block_until_ready(recs)
+        # a live step is active (counted in `steps`) or finds nothing to
+        # run (`deadlocked`, which ends the lane)
+        steps, deadlocked = jax.device_get(
+            (state.steps[0], state.deadlocked[0])
+        )
+        sp.set(steps=int(steps) + int(deadlocked), max_steps=max_steps)
     with telemetry.span("fetch", site="trace") as sp:
         recs = jax.device_get(recs)
         sp.set(bytes=sum(x.nbytes for x in jax.tree_util.tree_leaves(recs)))

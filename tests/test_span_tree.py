@@ -261,6 +261,22 @@ def test_trace_seed_matches_extract_trace_and_records_its_three_spans():
     steps = by["extract[trace]"].labels["steps"]
     assert 0 < steps <= 250
     assert steps == len({e.step for e in want})
+    assert by["scan[trace]"].labels["max_steps"] == 250
+    assert 0 < by["scan[trace]"].labels["steps"] <= 250
+
+    # a violating seed: the scan stops with the lane, short of max_steps,
+    # one step after the violation's
+    from test_trace import _SHORT, split_brain_spec
+
+    telemetry.disable()
+    telemetry.enable()
+    got = trace_seed(BatchedSim(split_brain_spec(), _SHORT), 0,
+                     max_steps=2_000)
+    scan = {r.label: r for r in telemetry.spans()}["scan[trace]"].labels
+    assert scan["max_steps"] == 2_000
+    assert 0 < scan["steps"] < scan["max_steps"]
+    violation = [e.step for e in got if e.kind == "violation"]
+    assert violation == [scan["steps"] - 1]
 
 
 # ------------------------------------------------------------ the step phases

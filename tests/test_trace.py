@@ -9,19 +9,24 @@ captured trace alone — no host twin — is enough to see the bug mechanics.
 import dataclasses
 import pytest
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from madsim_tpu import nemesis
 from madsim_tpu.tpu.spec import replace_handlers
 from madsim_tpu.tpu import (
     BatchedSim,
     BatchWorkload,
     SimConfig,
+    default_ctl,
     make_raft_spec,
     run_batch,
     trace_seed,
 )
+from madsim_tpu.tpu import nemesis as tpu_nemesis
 from madsim_tpu.tpu import raft as raft_mod
+from madsim_tpu.tpu.engine import merge_state, split_state
 from madsim_tpu.tpu.trace import extract_trace, format_trace
 
 
@@ -57,6 +62,75 @@ def split_brain_spec():
         return state._replace(commit=bogus_commit), out, timer
 
     return replace_handlers(spec, on_message=buggy_append_resp)
+
+
+def _scan_traced(sim, seed, n_steps, ctl=None):
+    """What `run_traced` must equal: a fixed-length scan of the step that
+    takes all `n_steps` steps, a done lane's as well."""
+    seeds = jnp.asarray([seed], jnp.uint32)
+    state = sim.init(seeds) if ctl is None else sim.init(seeds, ctl)
+
+    @jax.jit
+    def scan(hot, cold, const):
+        def body(carry, _):
+            h, c, rec = sim._step_split(*carry, const)
+            return (h, c), rec
+
+        (h, c), recs = jax.lax.scan(body, (hot, cold), None, length=n_steps)
+        return merge_state(h, c, const), recs
+
+    return scan(*split_state(state))
+
+
+_SHORT = partition_config(loss_rate=0.1, horizon_us=3_000_000)
+_PLAN = nemesis.FaultPlan(name="trace-loop", clauses=(
+    nemesis.Crash(interval_lo_us=400_000, interval_hi_us=1_500_000,
+                  down_lo_us=300_000, down_hi_us=1_000_000),
+    nemesis.Partition(interval_lo_us=300_000, interval_hi_us=1_200_000,
+                      heal_lo_us=400_000, heal_hi_us=1_500_000),
+))
+
+
+def _loop_case(case):
+    """(sim, seed, max_steps, ctl) of each way a traced lane ends."""
+    if case == "violation":  # the buggy spec breaks its invariant
+        return BatchedSim(split_brain_spec(), _SHORT), 0, 2_000, None
+    if case == "horizon":
+        return BatchedSim(make_raft_spec(5), _SHORT), 2, 2_000, None
+    if case == "cap":  # still running at max_steps
+        return BatchedSim(make_raft_spec(5), _SHORT), 2, 100, None
+    if case == "triage":  # the ctl's horizon, a third of the config's
+        sim = BatchedSim(split_brain_spec(), _SHORT, triage=True)
+        return sim, 2, 2_000, default_ctl(1, 1_000_000)
+    assert case == "lineage"
+    cfg = tpu_nemesis.compile_plan(_PLAN, SimConfig(horizon_us=3_000_000))
+    return BatchedSim(split_brain_spec(), cfg, lineage=True), 5, 2_000, None
+
+
+@pytest.mark.parametrize(
+    "case", ["violation", "horizon", "cap", "triage", "lineage"]
+)
+def test_run_traced_equals_the_fixed_length_scan(case):
+    # the traced loop stops with the lane, yet every record row and every
+    # final-state leaf is what stepping all max_steps steps gives
+    sim, seed, max_steps, ctl = _loop_case(case)
+    want = _scan_traced(sim, seed, max_steps, ctl)
+    got = sim.run_traced(seed, max_steps=max_steps, ctl=ctl)
+    tu = jax.tree_util
+    assert tu.tree_structure(got) == tu.tree_structure(want)
+    for (path, a), b in zip(tu.tree_leaves_with_path(got), tu.tree_leaves(want)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), tu.keystr(path)
+
+    state, recs = got
+    assert recs.clock.shape == (max_steps, 1)
+    live = int(state.steps[0]) + int(state.deadlocked[0])
+    if case == "cap":
+        assert not state.done[0] and live == max_steps
+    else:
+        assert state.done[0] and 0 < live < max_steps
+    assert bool(state.violated[0]) == (case in ("violation", "lineage"))
+    assert (recs.evt_eid is not None) == (case == "lineage")
+    assert (state.ctl is not None) == (case == "triage")
 
 
 @pytest.mark.deep

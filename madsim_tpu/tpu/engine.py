@@ -2595,88 +2595,88 @@ class BatchedSim:
         member_epoch = state.member_epoch
         nem_reconfig_at = nem_reconf_node = nem_reconfig_k = None
         if cfg.nem_reconfig_enabled:
-            nst = state.nem
-            member = bitpack.unpack_bits(state.member_p, N)  # bool [L,N]
-            reconf_due = active & (nst.reconfig_at <= t_next)
-            do_remove = reconf_due & (nst.reconf_node < 0)
-            do_join = reconf_due & (nst.reconf_node >= 0)
-            rk = nst.reconfig_k
-            # one gate per occurrence covers BOTH halves (k increments at
-            # the join, like clog/spike close their windows): a suppressed
-            # occurrence advances the timing machinery through its window
-            # but applies no membership change at all
-            reconf_en = (
-                _occ_on(ctl, "reconfig", rk) if self.triage
-                else jnp.ones((L,), jnp.bool_)
-            )
-            victim_d = prng.randint(
-                state.key0, NEM_SITE_RECONF_VICTIM, 0, N, index=rk
-            )
-            join_node = jnp.clip(nst.reconf_node, 0, N - 1)
-            ap_remove = do_remove & reconf_en
-            ap_join = do_join & reconf_en
-            remove_mask = ap_remove[:, None] & (node_ids == victim_d[:, None])
-            join_mask = ap_join[:, None] & (node_ids == join_node[:, None])
-            member = (member & ~remove_mask) | join_mask
-            # liveness and membership stay INDEPENDENT planes (a crashed
-            # member is dead_drops, a removed node nonmember_drops), but a
-            # remove also downs the node and a join revives it: a removed
-            # replica must not keep firing timers against the cluster
-            alive = (alive & ~remove_mask) | join_mask
-            member_epoch = member_epoch + (
-                ap_remove | ap_join
-            ).astype(jnp.int32)
-            # in-flight messages to the removed node are lost, like a
-            # crash (its pool slice empties; not counted as drops either)
-            valid = valid & ~remove_mask[:, :, None]
-            if self._B:
-                svalid = svalid & ~(
-                    ap_remove[:, None] & (strag.dst == victim_d[:, None])
+            # the remove and the join under their own scope in chaos
+            with jax.named_scope("membership"):
+                nst = state.nem
+                member = bitpack.unpack_bits(state.member_p, N)  # bool [L,N]
+                reconf_due = active & (nst.reconfig_at <= t_next)
+                do_remove = reconf_due & (nst.reconf_node < 0)
+                do_join = reconf_due & (nst.reconf_node >= 0)
+                rk = nst.reconfig_k
+                # one gate per occurrence covers BOTH halves (k increments at
+                # the join, like clog/spike close their windows): a suppressed
+                # occurrence advances the timing machinery through its window
+                # but applies no membership change at all
+                reconf_en = (
+                    _occ_on(ctl, "reconfig", rk) if self.triage
+                    else jnp.ones((L,), jnp.bool_)
                 )
-            # the joining node is a fresh replica: rebuilt through the
-            # real spec.init (the wipe-restart idiom), its first timer and
-            # declared absolute-time fields shifted to the join instant
-            ns_j, timer_j = self._v_init(rkeys, narange)
-            timer_j = jnp.asarray(timer_j, jnp.int32)
-            j_ok = (timer_j >= 0) & (timer_j < INF_GUARD)
-            timer_j = jnp.where(j_ok, timer_j + t_next[:, None], timer_j)
-            if cfg.nem_skew_enabled:
-                dj = timer_j - t_next[:, None]
-                sk_j = j_ok & (dj > 0)
-                timer_j = jnp.where(
-                    sk_j,
-                    t_next[:, None] + scale_delay_ppm(dj, state.nem.skew_ppm),
-                    timer_j,
+                victim_d = prng.randint(
+                    state.key0, NEM_SITE_RECONF_VICTIM, 0, N, index=rk
                 )
-            if spec.time_fields:
-                ns_j = ns_j._replace(**{
-                    f: getattr(ns_j, f)
-                    + t_next.reshape((L,) + (1,) * (getattr(ns_j, f).ndim - 1))
-                    for f in spec.time_fields
-                })
-            node = _tree_where(join_mask, ns_j, node)
-            timer = jnp.where(join_mask, timer_j, timer)
-            # schedule arithmetic: next toggle = previous toggle time plus
-            # an occurrence-indexed delta (never clock + delta)
-            down_d = prng.randint(
-                state.key0, NEM_SITE_RECONF_DUR, cfg.nem_reconfig_down_lo_us,
-                cfg.nem_reconfig_down_hi_us, index=rk,
-            )
-            next_d = prng.randint(
-                state.key0, NEM_SITE_RECONF_IV,
-                cfg.nem_reconfig_interval_lo_us,
-                cfg.nem_reconfig_interval_hi_us, index=rk + 1,
-            )
-            nem_reconfig_at = jnp.where(
-                do_remove, nst.reconfig_at + down_d,
-                jnp.where(do_join, nst.reconfig_at + next_d, nst.reconfig_at),
-            )
-            nem_reconf_node = jnp.where(
-                do_remove, victim_d, jnp.where(do_join, -1, nst.reconf_node)
-            )
-            nem_reconfig_k = rk + do_join.astype(jnp.int32)
-            tr_remove = jnp.where(ap_remove, victim_d, -1)
-            tr_join = jnp.where(ap_join, join_node, -1)
+                join_node = jnp.clip(nst.reconf_node, 0, N - 1)
+                ap_remove = do_remove & reconf_en
+                ap_join = do_join & reconf_en
+                remove_mask = ap_remove[:, None] & (node_ids == victim_d[:, None])
+                join_mask = ap_join[:, None] & (node_ids == join_node[:, None])
+                member = (member & ~remove_mask) | join_mask
+                # liveness and membership stay INDEPENDENT planes (a crashed
+                # member is dead_drops, a removed node nonmember_drops), but a
+                # remove also downs the node and a join revives it: a removed
+                # replica must not keep firing timers against the cluster
+                alive = (alive & ~remove_mask) | join_mask
+                member_epoch = member_epoch + (ap_remove | ap_join).astype(jnp.int32)
+                # in-flight messages to the removed node are lost, like a
+                # crash (its pool slice empties; not counted as drops either)
+                valid = valid & ~remove_mask[:, :, None]
+                if self._B:
+                    svalid = svalid & ~(
+                        ap_remove[:, None] & (strag.dst == victim_d[:, None])
+                    )
+                # the joining node is a fresh replica: rebuilt through the
+                # real spec.init (the wipe-restart idiom), its first timer and
+                # declared absolute-time fields shifted to the join instant
+                ns_j, timer_j = self._v_init(rkeys, narange)
+                timer_j = jnp.asarray(timer_j, jnp.int32)
+                j_ok = (timer_j >= 0) & (timer_j < INF_GUARD)
+                timer_j = jnp.where(j_ok, timer_j + t_next[:, None], timer_j)
+                if cfg.nem_skew_enabled:
+                    dj = timer_j - t_next[:, None]
+                    sk_j = j_ok & (dj > 0)
+                    timer_j = jnp.where(
+                        sk_j,
+                        t_next[:, None] + scale_delay_ppm(dj, state.nem.skew_ppm),
+                        timer_j,
+                    )
+                if spec.time_fields:
+                    ns_j = ns_j._replace(**{
+                        f: getattr(ns_j, f)
+                        + t_next.reshape((L,) + (1,) * (getattr(ns_j, f).ndim - 1))
+                        for f in spec.time_fields
+                    })
+                node = _tree_where(join_mask, ns_j, node)
+                timer = jnp.where(join_mask, timer_j, timer)
+                # schedule arithmetic: next toggle = previous toggle time plus
+                # an occurrence-indexed delta (never clock + delta)
+                down_d = prng.randint(
+                    state.key0, NEM_SITE_RECONF_DUR, cfg.nem_reconfig_down_lo_us,
+                    cfg.nem_reconfig_down_hi_us, index=rk,
+                )
+                next_d = prng.randint(
+                    state.key0, NEM_SITE_RECONF_IV,
+                    cfg.nem_reconfig_interval_lo_us,
+                    cfg.nem_reconfig_interval_hi_us, index=rk + 1,
+                )
+                nem_reconfig_at = jnp.where(
+                    do_remove, nst.reconfig_at + down_d,
+                    jnp.where(do_join, nst.reconfig_at + next_d, nst.reconfig_at),
+                )
+                nem_reconf_node = jnp.where(
+                    do_remove, victim_d, jnp.where(do_join, -1, nst.reconf_node)
+                )
+                nem_reconfig_k = rk + do_join.astype(jnp.int32)
+                tr_remove = jnp.where(ap_remove, victim_d, -1)
+                tr_join = jnp.where(ap_join, join_node, -1)
 
         # durability watermark ADVANCE (DiskFault plane, half 1 of 2):
         # re-snapshot the durable fields of every node whose sync counter
@@ -2926,12 +2926,12 @@ class BatchedSim:
         if cfg.nem_reconfig_enabled:
             # membership filter FIRST, so the two drop classes stay
             # disjoint: a send to a REMOVED node counts here (whatever its
-            # alive bit says), a send to a crashed member in dead_dropped
-            member_dst = (cand_dst_oh & member[:, None, :]).any(-1)
-            nonmember_dropped = (keep & ~member_dst).sum(
-                axis=1, dtype=jnp.int32
-            )
-            keep = keep & member_dst
+            # alive bit says), a send to a crashed member in dead_dropped;
+            # the filter has its own scope in network
+            with jax.named_scope("membership"):
+                member_dst = (cand_dst_oh & member[:, None, :]).any(-1)
+                nonmember_dropped = (keep & ~member_dst).sum(axis=1, dtype=jnp.int32)
+                keep = keep & member_dst
         else:
             nonmember_dropped = jnp.zeros((L,), jnp.int32)
         alive_dst = (cand_dst_oh & alive[:, None, :]).any(-1)

@@ -80,3 +80,16 @@ def test_lease_host_twin_clean_and_bug_on_both_faces():
     # workload wiring: host_repro present and runs end to end
     out = lease_workload(virtual_secs=4.0).host_repro(4)
     assert out["violations"] == 0
+
+
+def test_lease_at_the_etcd_deployment_s_ttl_and_keepalive():
+    """The benchmark's lease5 spec (etcd's 1.5 s TTL floor, clientv3's
+    TTL/3 keepalive) under lease_workload's chaos: clean, no pool
+    overflow, and the Reconfig clause fires."""
+    wl = lease_workload(virtual_secs=10.0)
+    spec = make_lease_spec(5, ttl_us=1_500_000, ka_interval_us=500_000)
+    state = BatchedSim(spec, wl.config).run(jnp.arange(256), max_steps=100_000)
+    s = summarize(state, spec)
+    assert s["violations"] == 0 and s["total_overflow"] == 0
+    assert s["fires_remove"] > 0 and s["fires_join"] > 0
+    assert np.asarray(state.done).all()

@@ -260,8 +260,8 @@ def phase_multichip(kind: str, n_chips: int,
     n = n_chips * lanes_per_chip
     seeds = range(n)
 
-    # run_batch's default chunk (65,536 seeds, split over the mesh): the
-    # summary's exact 64-bit sums refuse a larger chunk
+    # run_batch's default chunk (32,768 lanes a chip, at most 65,536 in
+    # all: the summary's exact 64-bit sums refuse a larger chunk)
     t0 = time.perf_counter()
     mesh = run_batch(seeds, wl, mesh="auto", sim=sim)
     mesh_s = time.perf_counter() - t0

@@ -40,12 +40,25 @@ import jax
 import numpy as np
 
 from .. import telemetry
-from .engine import (BatchedSim, DEFAULT_DISPATCH_STEPS, SimState,
-                     summarize)
+from .engine import (BatchedSim, DEFAULT_DISPATCH_STEPS, SUM64_MAX_LANES,
+                     SimState, summarize)
 from .spec import ProtocolSpec, SimConfig
 
-# lanes per device dispatch: bounds peak memory for huge sweeps
-DEFAULT_CHUNK = 65_536
+# Lanes PER DEVICE of one chunked dispatch. Compiled for a v5e, the headline
+# raft `_run`'s temporaries are 0.14x its argument bytes at 16,384 lanes,
+# 1.07x at 32,768 and 1.97x at 65,536, where the step spills through extra
+# async copies: a lane-iteration costs 37.95, 43.77 and 60.73 ns on a v5e.
+# 16,384 lanes would double the chunk boundaries, whose unoverlapped decode
+# costs a short-step workload (lease5) about 4.5% end to end (PERF.md).
+DEFAULT_CHUNK = 32_768
+
+
+def default_chunk(n_devices: int) -> int:
+    """run_batch's chunk when the caller names none: DEFAULT_CHUNK lanes a
+    device, capped at the lanes `engine._sum64` sums exactly in one chunk's
+    summary (65,536: one chip runs 32,768 lanes a dispatch, four chips
+    16,384 each)."""
+    return min(DEFAULT_CHUNK * n_devices, SUM64_MAX_LANES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -365,6 +378,16 @@ def run_batch(
     inputs are unchanged; only the host's read order moves), which the
     pipelining-determinism tests pin.
 
+    `chunk` is the lanes of one dispatch on the chunked path (on the
+    refill path, the seeds of one queue segment). None takes
+    `default_chunk`: DEFAULT_CHUNK = 32,768 lanes a device, at most
+    65,536 in all, the widest lane axis `engine._sum64` sums exactly —
+    one chip runs 32,768 lanes a dispatch, four chips 16,384 each. At
+    65,536 lanes on one v5e the headline step's compiled temporaries are
+    twice its arguments and a lane-iteration costs 1.39x more (the table
+    at DEFAULT_CHUNK). An explicit `chunk`, then a tuned one, wins. The summary reports the
+    plan that ran: `chunk` (lanes a dispatch) and `chunks` (dispatches).
+
     `coverage` turns on the per-lane coverage instrumentation (the
     explorer's novelty signal, madsim_tpu/explore.py): the result carries a
     `LaneCoverage` and the summary a `coverage_bits` union count. Off by
@@ -441,8 +464,9 @@ def run_batch(
             # instead of killing the sweep — a cache can only ever be a
             # throughput upgrade, never a crash
             mesh = _tune._mesh_for(tn["devices"], cached=True)
+    mesh = resolve_mesh(mesh)
     if chunk is None:
-        chunk = DEFAULT_CHUNK
+        chunk = default_chunk(mesh.devices.size if mesh is not None else 1)
     if pipeline is None:
         pipeline = True
     if refill is None:
@@ -479,7 +503,7 @@ def run_batch(
     if dispatch_steps is None:
         dispatch_steps = DEFAULT_DISPATCH_STEPS
     common = dict(
-        chunk=chunk, mesh=resolve_mesh(mesh), pipeline=pipeline,
+        chunk=chunk, mesh=mesh, pipeline=pipeline,
         coverage=coverage, check_determinism=check_determinism,
         repro_on_host=repro_on_host, max_host_repros=max_host_repros,
         max_traces=max_traces, shrink_on_violation=shrink_on_violation,
@@ -540,7 +564,8 @@ def _run_batch_chunked(
             part_in = np.concatenate([part, np.repeat(part[:1], pad)])
         else:
             part_in = part
-        with telemetry.span("dispatch", site="run_batch", off=off):
+        with telemetry.span("dispatch", site="run_batch", off=off,
+                            lanes=part_in.size):
             st = sim.run(
                 part_in, max_steps=workload.max_steps,
                 dispatch_steps=dispatch_steps, mesh=mesh,
@@ -635,6 +660,10 @@ def _run_batch_chunked(
     # those against the global seeds array mislabels lanes on chunked runs)
     totals["violation_lanes"] = np.nonzero(violated)[0].tolist()[:32]
     totals["n_devices"] = n_dev
+    # the chunk plan that ran: lanes a dispatch (before device padding)
+    # and dispatches in the call
+    totals["chunk"] = min(chunk, seeds_arr.size)
+    totals["chunks"] = -(-seeds_arr.size // chunk)
     # chaos-coverage report: every enabled fault clause should fire
     # somewhere in a batch this size; a zero is a dead clause
     from .nemesis import coverage_report, enabled_fire_kinds

@@ -4801,6 +4801,10 @@ def abs_time_us(state: SimState):
     )
 
 
+# the widest lane axis `_sum64` sums exactly (run_batch caps its chunks here)
+SUM64_MAX_LANES = 65_536
+
+
 def _sum64(x: jnp.ndarray, axis=0):
     """Exact lane sum of a non-negative i32 tensor WITHOUT int64 (x64 mode
     is off): split into 16-bit halves, sum each in u32 — hi * 2^16 + lo is
@@ -4808,10 +4812,10 @@ def _sum64(x: jnp.ndarray, axis=0):
     only for lanes <= 65536 (values < 2^31), so that bound is ENFORCED:
     a bigger batch must be summarized in chunks (run_batch already
     chunks), not allowed to wrap the u32 partials silently."""
-    if x.shape[axis] > 65536:
+    if x.shape[axis] > SUM64_MAX_LANES:
         raise ValueError(
-            f"_sum64: lane axis {x.shape[axis]} > 65536 would overflow "
-            "the u32 partial sums — summarize in chunks"
+            f"_sum64: lane axis {x.shape[axis]} > {SUM64_MAX_LANES} "
+            "would overflow the u32 partial sums — summarize in chunks"
         )
     xu = x.astype(jnp.uint32)
     return (

@@ -755,7 +755,9 @@ def tune_workload(
     `tier_b_gate` passes — otherwise the defaults stand."""
     import dataclasses as dc
 
-    from .tpu.batch import DEFAULT_CHUNK, run_batch
+    import jax
+
+    from .tpu.batch import default_chunk, run_batch
     from .tpu.engine import BatchedSim
     from .tpu.spec import SimConfig
 
@@ -765,7 +767,8 @@ def tune_workload(
     from .tpu.engine import DEFAULT_DISPATCH_STEPS
 
     default = {
-        "chunk": min(DEFAULT_CHUNK, n),
+        # run_batch's own default on this host (devices 0 = every one)
+        "chunk": min(default_chunk(len(jax.devices())), n),
         "dispatch_steps": DEFAULT_DISPATCH_STEPS,
         "pipeline": True, "refill_lanes": 0, "devices": 0,
     }

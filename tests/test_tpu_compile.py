@@ -7,7 +7,9 @@ programs are the ones `chip_smoke.py` runs on the chip: the headline raft
 sweep segment (`BatchedSim._run`) at 32,768 lanes on one chip, the kv
 linearizability segment at 16,384 lanes, and the raft segment
 lane-sharded over the four described chips at 4 x 32,768 lanes (what
-`run_batch(mesh="auto")` dispatches on a four-chip host).
+`run_batch(mesh="auto")` dispatches on a four-chip host). The raft segment
+at run_batch's one-chip default width must also keep its temporaries
+under 1.5x its arguments: past that width the step spills.
 
 Only one process at a time may load the TPU library, so the topology is
 described inside a fixture of this one file — never at import time — and
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 
 from bench import raft_bench_config
 from madsim_tpu.tpu import BatchedSim, make_raft_spec
+from madsim_tpu.tpu.batch import default_chunk
 from madsim_tpu.tpu.kv import kv_workload
 
 CHIP_HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -81,17 +84,42 @@ def _headline_sim():
     )
 
 
-@pytest.mark.parametrize("workload,lanes", [("raft", 32_768), ("kv", 16_384)])
-def test_sweep_segment_compiles_for_one_v5e(topo, workload, lanes):
+@pytest.fixture(scope="module")
+def compile_one_chip(topo):
+    """Compile a sweep segment for one described chip, once per
+    (workload, lanes) in this module."""
     from jax.sharding import SingleDeviceSharding
 
-    if workload == "raft":
-        sim = _headline_sim()
-    else:
-        wl = kv_workload(virtual_secs=10.0)
-        sim = BatchedSim(wl.spec, wl.config)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    _compile_run(sim, _state_shapes(sim, lanes, lambda x: one_chip))
+    compiled = {}
+
+    def compile_(workload, lanes):
+        if (workload, lanes) not in compiled:
+            if workload == "raft":
+                sim = _headline_sim()
+            else:
+                wl = kv_workload(virtual_secs=10.0)
+                sim = BatchedSim(wl.spec, wl.config)
+            compiled[workload, lanes] = _compile_run(
+                sim, _state_shapes(sim, lanes, lambda x: one_chip))
+        return compiled[workload, lanes]
+
+    return compile_
+
+
+@pytest.mark.parametrize("workload,lanes", [("raft", 32_768), ("kv", 16_384)])
+def test_sweep_segment_compiles_for_one_v5e(compile_one_chip, workload,
+                                            lanes):
+    compile_one_chip(workload, lanes)
+
+
+def test_default_width_keeps_the_raft_step_from_spilling(compile_one_chip):
+    # temp / argument bytes of the headline segment on a v5e: 0.14 at
+    # 16,384 lanes, 1.07 at 32,768, 1.97 at 65,536, where the step spills
+    # through extra async copies and a lane-iteration costs 1.39x more
+    m = compile_one_chip("raft", default_chunk(1)).memory_analysis()
+    assert m.temp_size_in_bytes < 1.5 * m.argument_size_in_bytes, (
+        default_chunk(1), m.temp_size_in_bytes, m.argument_size_in_bytes)
 
 
 def test_lane_sharded_segment_compiles_for_four_v5e(topo):
